@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import common as C
-from repro.core.probe import HBM_BW, PEAK_FLOPS, vector_from_compiled
+from repro.core.probe import vector_from_compiled
 from repro.models import layers as L
 
 

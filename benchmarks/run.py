@@ -20,6 +20,7 @@ from benchmarks import (
     table3_turnaround, table4_slowdown,
 )
 from benchmarks.common import save_json
+from repro.launch.compile_cache import enable_compile_cache
 
 EXPERIMENTS = {
     "fig4": fig4_alg2_vs_alg3.run,
@@ -88,6 +89,7 @@ def main() -> None:
                     help="forward smoke mode to the experiments that "
                          f"support it ({', '.join(sorted(SMOKE_CAPABLE))})")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.only:
         names = [n.strip() for n in args.only.split(",") if n.strip()]
         unknown = [n for n in names if n not in EXPERIMENTS]

@@ -47,10 +47,14 @@ def make_train_job(arch: str, idx: int, steps: int = 3) -> ExecJob:
     state = {"params": params, "opt": opt_state}
 
     def runner(device):
+        # the job computes on the device it was placed on
         jstep = jax.jit(step)
+        state["params"], state["opt"] = jax.device_put(
+            (state["params"], state["opt"]), device)
+        on_dev = jax.device_put(batch, device)
         for _ in range(steps):
             state["params"], state["opt"], m = jstep(
-                state["params"], state["opt"], batch)
+                state["params"], state["opt"], on_dev)
         jax.block_until_ready(m["loss"])
 
     unit = UnitTask(fn=None, memobjs=frozenset({name}), resources=vec,
@@ -73,7 +77,8 @@ def make_serve_job(arch: str, idx: int) -> ExecJob:
     name = f"serve-{arch}-{idx}"
 
     def runner(device):
-        logits, cache = jax.jit(prefill)(params, batch)
+        logits, cache = jax.jit(prefill)(
+            *jax.device_put((params, batch), device))
         jax.block_until_ready(logits)
 
     unit = UnitTask(fn=None, memobjs=frozenset({name}), resources=vec,
@@ -158,7 +163,7 @@ def main():
     vec = probe_fn(prefill, params, fleet_batch)
 
     def decode_runner(device):
-        logits, _ = prefill(params, fleet_batch)
+        logits, _ = prefill(*jax.device_put((params, fleet_batch), device))
         jax.block_until_ready(logits)
 
     t0 = time.time()

@@ -417,6 +417,12 @@ class Cluster:
         else:
             self.sched.revive(device)
 
+    def jax_device(self, index: int):
+        """The jax device behind scheduler device ``index`` on the live
+        backend (where a runner placed there computes); None on the sim
+        backend, which computes nothing."""
+        return self._ex.device_map[index] if self._ex is not None else None
+
     @property
     def now(self) -> float:
         """Current time on the backend's clock (virtual for sim)."""

@@ -29,12 +29,15 @@ per in-flight job spinning ``task_begin`` in a sleep(poll) loop — as the
 baseline ``benchmarks/bench_executor.py`` measures the event-driven engine
 against.
 
-On this CPU-only container jax exposes one device, so the executor virtualizes
-``num_devices`` logical devices over it: placement, memory accounting and
-OOM/crash semantics are per *virtual* device (exactly the scheduler's view),
-while the arithmetic runs wherever jax puts it. On real hardware
-``jax.devices()`` replaces the virtual table and ``LazyBuffer.bind`` receives
-the physical device — nothing else changes.
+Device table (``device_table``): on an accelerator every scheduler device
+IS one attached chip — the scheduler may claim no more devices than are
+attached and no more HBM per device than the chip's
+``memory_stats()["bytes_limit"]``, or admission would promise memory the
+chip does not have; each runner receives its placed ``jax.Device`` (or the
+gang's device list) and must put its arrays there. On the CPU backend only
+(tests, rehearsals) any number of scheduler devices map round-robin onto the
+attached CPU devices: placement, memory accounting and OOM/crash semantics
+stay per *virtual* device while the arithmetic runs wherever jax puts it.
 """
 from __future__ import annotations
 
@@ -42,12 +45,12 @@ import dataclasses
 import queue as queue_mod
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
 from repro.core import lazy
-from repro.core.scheduler.base import DEADLINE_SHED, Scheduler
+from repro.core.scheduler.base import DEADLINE_SHED, DEFAULT_HBM, Scheduler
 from repro.core.task import Job, Task
 from repro.core.topology import placement_devices
 from repro.obs import events as obs
@@ -107,6 +110,48 @@ class ExecJob:
     on_preempt: Optional[Callable[[Task], None]] = None
 
 
+def device_capacity(devices: Optional[Sequence[object]] = None
+                    ) -> Tuple[int, int]:
+    """(device count, per-device HBM bytes) of the attached devices — what a
+    scheduler for this process should be built with. The capacity is the
+    smallest ``bytes_limit`` the devices report; the CPU backend reports
+    none and gets ``DEFAULT_HBM`` per (virtual) device."""
+    devs = list(devices) if devices is not None else list(jax.devices())
+    if devs[0].platform == "cpu":
+        return len(devs), DEFAULT_HBM
+    return len(devs), min(_bytes_limit(d) for d in devs)
+
+
+def _bytes_limit(dev) -> int:
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit is None:
+        raise ValueError(f"{dev} reports no bytes_limit: its usable HBM "
+                         "cannot be verified")
+    return int(limit)
+
+
+def device_table(scheduler: Scheduler,
+                 devices: Sequence[object]) -> List[object]:
+    """Scheduler device index -> jax device (see the module docstring):
+    one-to-one and capacity-checked on an accelerator, round-robin on the
+    CPU backend only."""
+    n = len(scheduler.devices)
+    real = list(devices)
+    if real[0].platform == "cpu":
+        return [real[i % len(real)] for i in range(n)]
+    if n > len(real):
+        raise ValueError(
+            f"scheduler has {n} devices but only {len(real)} "
+            f"{real[0].platform} device(s) are attached")
+    for dev, jd in zip(scheduler.devices, real):
+        limit = _bytes_limit(jd)
+        if dev.total_hbm > limit:
+            raise ValueError(
+                f"scheduler device {dev.index} claims {dev.total_hbm} B of "
+                f"HBM but {jd} has bytes_limit {limit} B")
+    return real[:n]
+
+
 def _empty_stats() -> Dict[str, float]:
     return {"makespan_s": 0.0, "throughput_jobs_per_s": 0.0,
             "completed": 0, "crashed": 0, "mean_turnaround_s": 0.0,
@@ -151,11 +196,8 @@ class Executor:
         self.sched = scheduler
         self.workers = workers
         self.poll = poll_interval  # kept for API compat (PollingExecutor uses it)
-        n = len(scheduler.devices)
-        real = list(devices) if devices is not None else list(jax.devices())
-        # virtual device i -> a real jax device (round-robin over whatever
-        # the platform exposes; 1 CPU device here, n TPUs in production)
-        self.device_map = [real[i % len(real)] for i in range(n)]
+        self.device_map = device_table(
+            scheduler, devices if devices is not None else jax.devices())
         self.records: List[ExecRecord] = []
         self._rec_lock = threading.Lock()
         # preemptive scheduler: observe evictions so the victim's running
@@ -466,6 +508,7 @@ class Executor:
             return  # job already resolved: stale straggler dispatch
         lock = self._attempt_locks.setdefault(task.uid, threading.Lock())
         crashed = False
+        error = ""
         t_start = None
         with lock:
             with self._signal_lock:
@@ -497,8 +540,11 @@ class Executor:
                     bound = (self.device_map[lead] if len(devs) == 1
                              else [self.device_map[d] for d in devs])
                     jr.ej.runners[item.task_idx](bound)
-                except Exception:
+                except Exception as e:
+                    # keep the cause: a compile error or a real
+                    # RESOURCE_EXHAUSTED must not read as a bare crash count
                     crashed = True
+                    error = f"{task.name}: {type(e).__name__}: {e}"
         if t_start is None:
             # superseded between pool pickup and dispatch. If the fresh
             # incarnation meanwhile finished the whole job, _finish's
@@ -515,9 +561,10 @@ class Executor:
         if not current:
             return
         if crashed:
+            jr.ej.job.error = error
             if tr is not None:
                 tr.emit(obs.CRASH, task.uid, task.name, lead, item.epoch,
-                        data={"reason": "runner"})
+                        data={"reason": "runner", "error": error})
             now = time.monotonic()
             self._record(jr, ExecRecord(
                 jr.ej.job.name, task.name, lead, jr.t_queue,

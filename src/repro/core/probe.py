@@ -18,14 +18,10 @@ import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+from jax.sharding import SingleDeviceSharding
 
+from repro.core.chips import device_peaks
 from repro.core.task import ResourceVector
-
-# TPU v5e-class constants (same as launch.roofline; kept here so core/ has no
-# circular dep on launch/)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
 
 
 def _mem_bytes(compiled) -> int:
@@ -63,18 +59,19 @@ def vector_from_compiled(compiled, *, chips: int = 1,
     consumes. Callers pass measured/calibrated profiles (workloads.py) or
     leave (1, 1) for ideal kernels.
     """
+    pk = device_peaks()
     cost = _cost(compiled)
     flops = float(flops_override if flops_override is not None
                   else cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))
     core_eff, bw_eff = efficiency
-    compute_s = flops / (chips * PEAK_FLOPS * core_eff)
-    memory_s = bytes_acc / (HBM_BW * bw_eff)
-    collective_s = collective_bytes / ICI_BW
+    compute_s = flops / (chips * pk.flops * core_eff)
+    memory_s = bytes_acc / (pk.hbm_bw * bw_eff)
+    collective_s = collective_bytes / pk.ici_bw
     est = max(compute_s, memory_s, collective_s, 1e-9)
     # demands: achieved share of the raw roof, per wall-second
-    compute_share = (flops / (chips * PEAK_FLOPS)) / est
-    memory_share = (bytes_acc / HBM_BW) / est
+    compute_share = (flops / (chips * pk.flops)) / est
+    memory_share = (bytes_acc / pk.hbm_bw) / est
     return ResourceVector(
         hbm_bytes=_mem_bytes(compiled),
         flops=flops * work_scale,
@@ -94,8 +91,18 @@ _probe_cache: Dict[Tuple, Any] = {}
 
 
 def _abstractify(tree):
-    return jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    """Shapes of ``tree`` with the shardings the probed call will see: a
+    committed array (or a ShapeDtypeStruct with a sharding) keeps its own,
+    anything else is taken to run on the default device. The probe then
+    lowers the very program a call committed to that device runs, and the
+    call reuses the probe's compile instead of compiling again."""
+    default = SingleDeviceSharding(jax.devices()[0])
+
+    def leaf(a):
+        sh = a.sharding if isinstance(a, jax.ShapeDtypeStruct) \
+            or getattr(a, "committed", False) else None
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh or default)
+    return jax.tree_util.tree_map(leaf, tree)
 
 
 def probe_fn(fn: Callable, *args, chips: int = 1, work_scale: float = 1.0,
@@ -106,7 +113,9 @@ def probe_fn(fn: Callable, *args, chips: int = 1, work_scale: float = 1.0,
 
     This is the instrumented ``task_begin`` of the paper: called right before
     launch, it conveys the resource needs to the scheduler. AOT compilation
-    happens once per (fn, shape-signature).
+    happens once per (fn, shape-signature). A ``jax.jit``-wrapped ``fn`` is
+    lowered as it stands, so its donations (aliased outputs) count as they
+    will when it runs.
     """
     sds = _abstractify(args)
     leaves, treedef = jax.tree_util.tree_flatten(sds)
@@ -114,7 +123,8 @@ def probe_fn(fn: Callable, *args, chips: int = 1, work_scale: float = 1.0,
            tuple((tuple(l.shape), str(l.dtype)) for l in leaves))
     compiled = _probe_cache.get(key)
     if compiled is None:
-        compiled = jax.jit(fn).lower(*sds).compile()
+        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+        compiled = jitted.lower(*sds).compile()
         if len(_probe_cache) < 512:
             _probe_cache[key] = compiled
     return vector_from_compiled(compiled, chips=chips, work_scale=work_scale,

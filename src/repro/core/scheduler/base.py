@@ -115,6 +115,9 @@ class DeviceState:
     # MGB Alg. 2 feasibility check is O(1) per candidate device instead of
     # O(residents) (it runs once per device per placement attempt)
     used_slots: int = 0
+    # high-water of used_hbm: the most this device's admitted reservations
+    # ever summed to (checked against what the chip itself observed)
+    peak_hbm: int = 0
 
     @property
     def free_hbm(self) -> int:
@@ -131,6 +134,7 @@ class DeviceState:
 
     def admit(self, task: Task) -> None:
         self.used_hbm += task.resources.hbm_bytes
+        self.peak_hbm = max(self.peak_hbm, self.used_hbm)
         self.used_slots += slots_needed(task)
         self.residents[task.uid] = task
 

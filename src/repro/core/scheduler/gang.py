@@ -308,6 +308,7 @@ class GangScheduler(WaiterQueueMixin):
             # not DeviceState.admit(): a gang charges each member its
             # per-chip share, not the whole-gang footprint
             d.used_hbm += per_chip
+            d.peak_hbm = max(d.peak_hbm, d.used_hbm)
             d.used_slots += need
             d.residents[task.uid] = task
         self.topo.reserve_links(task.uid, group, r)

@@ -37,12 +37,13 @@ import dataclasses
 import heapq
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
+from repro.core.chips import V5E
 from repro.core.scheduler.base import DEFAULT_HBM, DeviceState
 from repro.core.task import ResourceVector
 
-# bandwidth constants (match repro.core.probe's roofline): one ICI link of a
-# v5e-class chip, and one aggregate DCN edge between two pods
-ICI_BW = 50e9
+# bandwidth constants: one ICI link of a v5e (repro.core.chips), and one
+# aggregate DCN edge between two pods
+ICI_BW = V5E.ici_bw
 DCN_BW = 12.5e9
 
 Cell = Tuple[int, int, int]            # (pod, row, col)

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.chips import V5E
 from repro.core.probe import probe_fn
 from repro.core.task import Job, ResourceVector, Task, UnitTask
 
@@ -308,11 +309,7 @@ def nn_mix(seed: int, n_jobs: int = 128) -> List[Job]:
 # group personality (collective share, lockstep duration) is a property of
 # the sharding, which these knobs model directly.
 
-# v5e-class peaks, for internally-consistent synthetic flops/bytes numbers
-_PEAK_FLOPS = 197e12
-_HBM_BW = 819e9
-_ICI_BW = 50e9
-
+# synthetic flops/bytes numbers are sized against the v5e's peaks
 
 def make_gang_job(rng: np.random.Generator, *, chips: int, name: str,
                   per_chip_gb: Tuple[float, float] = (2.0, 6.0),
@@ -326,12 +323,12 @@ def make_gang_job(rng: np.random.Generator, *, chips: int, name: str,
     share = rng.uniform(*collective_share)
     demand = rng.uniform(0.4, 0.9)
     # per-link ring payload that occupies `share` of a link for compute_s
-    collective_bytes = share * compute_s * _ICI_BW
-    est = max(compute_s, collective_bytes / _ICI_BW)  # = compute_s (share<=1)
+    collective_bytes = share * compute_s * V5E.ici_bw
+    est = max(compute_s, collective_bytes / V5E.ici_bw)  # = compute_s (share<=1)
     vec = ResourceVector(
         hbm_bytes=int(per_chip * chips),
-        flops=demand * compute_s * _PEAK_FLOPS * chips,
-        bytes_accessed=0.5 * demand * compute_s * _HBM_BW * chips,
+        flops=demand * compute_s * V5E.flops * chips,
+        bytes_accessed=0.5 * demand * compute_s * V5E.hbm_bw * chips,
         collective_bytes=collective_bytes,
         est_seconds=est, core_demand=demand, bw_demand=0.5 * demand,
         chips=chips)

@@ -20,7 +20,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -55,10 +54,10 @@ def make_pipeline_forward(layer_fn: Callable, mesh, *, n_micro: int,
         xs = x.reshape((n_micro, mb) + x.shape[1:])
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(axis), P()),
             out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         def _run(sp, xs):
             sp = jax.tree_util.tree_map(lambda w: w[0], sp)  # local slice
             stage = jax.lax.axis_index(axis)

@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU —
-the kernels are written for TPU BlockSpec tiling and validated against the
-ref.py oracles in interpret mode.
+``interpret=None`` resolves through ``_default_interpret``: compiled by
+Mosaic on a TPU, run by the Pallas interpreter on any other backend (the
+CPU test suite, which checks them against the ref.py oracles). The kernel
+modules themselves compile by default; interpret mode is only ever chosen
+here or passed explicitly by a test.
 """
 from __future__ import annotations
 

@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps):
 @functools.partial(jax.jit,
                    static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: bool = False):
     """x: [..., d]; scale: [d]. Matches repro.models.layers.rms_norm."""
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -47,6 +47,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale.reshape(1, d))
     if pad:
         out = out[:rows]

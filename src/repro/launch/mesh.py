@@ -12,17 +12,24 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    """Generic helper for tests/examples (e.g. 1x1 CPU mesh)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, devices=None):
+    """Mesh with ``Auto`` axes (``jax.make_mesh`` defaults to ``Explicit``,
+    under which the sharding rules in ``repro.dist.sharding`` — written for
+    GSPMD propagation — raise on gathers such as the embedding lookup).
+    ``devices`` pins the mesh to an explicit device list, e.g. the chips of
+    a gang reservation; None takes the first ``prod(shape)`` devices."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def data_axes(mesh) -> tuple:

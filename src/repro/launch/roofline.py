@@ -21,10 +21,10 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-# TPU v5e-class hardware constants (per the assignment brief)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# the dry-run compiles for a v5e production mesh: its roofline is the v5e's
+# whatever device this process sees
+from repro.core.chips import V5E
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -80,9 +80,9 @@ class Roofline:
     roofline_fraction: float = 0.0  # useful compute time / max(term)
 
     def finalize(self) -> "Roofline":
-        self.compute_s = self.analytic_flops_global / (self.chips * PEAK_FLOPS)
-        self.memory_s = self.bytes_per_device / HBM_BW
-        self.collective_s = self.collective_per_device / ICI_BW
+        self.compute_s = self.analytic_flops_global / (self.chips * V5E.flops)
+        self.memory_s = self.bytes_per_device / V5E.hbm_bw
+        self.collective_s = self.collective_per_device / V5E.ici_bw
         terms = {"compute": self.compute_s, "memory": self.memory_s,
                  "collective": self.collective_s}
         self.dominant = max(terms, key=terms.get)
@@ -90,7 +90,7 @@ class Roofline:
                              if self.analytic_flops_global else 0.0)
         # fraction of roofline: time the USEFUL model flops would take at peak
         # vs. the bounding term of the compiled program
-        useful_s = self.model_flops / (self.chips * PEAK_FLOPS)
+        useful_s = self.model_flops / (self.chips * V5E.flops)
         bound = max(terms.values())
         self.roofline_fraction = useful_s / bound if bound else 0.0
         return self

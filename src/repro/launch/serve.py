@@ -17,7 +17,12 @@ scheduler, with two serving disciplines over the same open-arrival
   scheduler (memory-safe batch growth).
 
 Both report per-request TTFT (arrival → first token) and TPOT (mean
-inter-token time over the decode tail).
+inter-token time over the decode tail). The scheduler is built from the
+attached devices: one scheduler device per chip, each with the chip's
+``bytes_limit`` of HBM (``--num-devices`` more than are attached is refused
+on an accelerator; on the CPU backend extra devices are virtual). The
+reduced config runs by default; ``--full`` serves the published one with
+bf16 weights.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b \
@@ -34,10 +39,11 @@ import numpy as np
 
 from repro.configs.registry import ARCHS, get_arch
 from repro.core.cluster import Cluster, JobStatus
-from repro.core.executor import ExecJob
+from repro.core.executor import ExecJob, device_capacity
 from repro.core.probe import probe_fn
 from repro.core.scheduler import MGBAlg3Scheduler, PreemptiveAlg3Scheduler
 from repro.core.task import Job, Task, UnitTask
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.serve.decode import greedy_generate, make_prefill_step
 
@@ -50,19 +56,39 @@ def _pct(xs, p):
     return xs[i]
 
 
+def _model(arch: str, full: bool, seed: int):
+    """(config, weights from ``seed``): the published config in bf16 when
+    ``full``, else the reduced CPU-sized variant in f32."""
+    cfg = get_arch(arch)
+    if not full:
+        return cfg.reduced(), init_params(cfg.reduced(),
+                                          jax.random.PRNGKey(seed))
+    return cfg, init_params(cfg, jax.random.PRNGKey(seed),
+                            param_dtype=jnp.bfloat16)
+
+
+def _fleet(num_devices):
+    """(scheduler device count, per-device HBM) from the attached devices;
+    ``num_devices`` overrides the count (virtual devices on the CPU)."""
+    n, hbm = device_capacity()
+    return (num_devices or n), hbm
+
+
 def serve(arch: str, *, requests: int = 16, batch: int = 4,
           prompt_len: int = 64, gen_len: int = 32, seed: int = 0,
-          num_devices: int = 2, workers: int = 0,
+          num_devices: int = 0, workers: int = 0,
           deadline_s: float = 5.0, shed_late: bool = False,
-          preempt: bool = False, trace_path: str = None) -> dict:
-    cfg = get_arch(arch).reduced()
-    params = init_params(cfg, jax.random.PRNGKey(seed))
+          preempt: bool = False, full: bool = False,
+          trace_path: str = None) -> dict:
+    cfg, params = _model(arch, full, seed)
     prefill = jax.jit(make_prefill_step(cfg, attn_impl="flash_jnp"))
+    num_devices, hbm = _fleet(num_devices)
     # preempt turns the deadline into the ENFORCEMENT half shedding cannot
     # give: an arriving earlier-deadline request may evict a resident one
     # (same priority class, EDF outranking) instead of waiting behind it
-    sched = (PreemptiveAlg3Scheduler(num_devices) if preempt
-             else MGBAlg3Scheduler(num_devices))
+    sched = (PreemptiveAlg3Scheduler(num_devices, hbm_per_device=hbm)
+             if preempt else MGBAlg3Scheduler(num_devices,
+                                              hbm_per_device=hbm))
 
     rng = np.random.default_rng(seed)
     n_batches = (requests + batch - 1) // batch
@@ -87,6 +113,9 @@ def serve(arch: str, *, requests: int = 16, batch: int = 4,
                       shed_late=shed_late, preempt=preempt or None,
                       trace=bool(trace_path))
     handles = []
+    # one weight replica on each device a batch can be placed on
+    replicas = {d: jax.device_put(params, d) for d in
+                {cluster.jax_device(k) for k in range(num_devices)}}
     # per-batch wall-clock marks filled by the runner: (submit, first-token,
     # last-token) — the per-request TTFT/TPOT instrumentation
     marks = [[0.0, -1.0, -1.0] for _ in range(n_batches)]
@@ -103,11 +132,13 @@ def serve(arch: str, *, requests: int = 16, batch: int = 4,
                 (batch, prompt_len, cfg.d_model), dtype=np.float32))
 
         def runner(device, b=b, i=i):
-            logits, cache = prefill(params, b)
+            # a placed batch runs on its device, against that replica
+            p = replicas[device]
+            logits, cache = prefill(p, jax.device_put(b, device))
             first = jax.block_until_ready(
                 jnp.argmax(logits, axis=-1).astype(jnp.int32))
             marks[i][1] = time.time()
-            out, _ = greedy_generate(cfg, params, cache, first, prompt_len,
+            out, _ = greedy_generate(cfg, p, cache, first, prompt_len,
                                      gen_len - 1)
             jax.block_until_ready(out)
             marks[i][2] = time.time()
@@ -165,38 +196,58 @@ def serve(arch: str, *, requests: int = 16, batch: int = 4,
 
 def serve_continuous(arch: str, *, requests: int = 16, batch: int = 4,
                      prompt_len: int = 64, gen_len: int = 32, seed: int = 0,
-                     num_devices: int = 2, workers: int = 0,
+                     num_devices: int = 0, workers: int = 0,
                      ttft_slo_s: float = 5.0, tpot_slo_s: float = 1.0,
-                     shed_late: bool = False,
+                     shed_late: bool = False, full: bool = False,
+                     prompt_lens=None, in_flight=None,
                      trace_path: str = None) -> dict:
     """Continuous-batching counterpart: per-request streaming through
-    ServeEngine; ``batch`` becomes each decode loop's max rows."""
+    ServeEngine; ``batch`` becomes each decode loop's max rows.
+
+    ``prompt_lens`` gives each request its own prompt length (default:
+    ``requests`` prompts of ``prompt_len``). ``in_flight(cluster, engine)``
+    runs once every request is submitted and before the drain: the hook
+    for other work sharing the cluster while requests are in flight. The
+    result's ``engine`` keeps the served requests (prompts, tokens,
+    placements) and the model for checking them."""
     from repro.serve.engine import SLO, JaxModel, ServeEngine
 
-    cfg = get_arch(arch).reduced()
-    params = init_params(cfg, jax.random.PRNGKey(seed))
+    cfg, params = _model(arch, full, seed)
+    lens = list(prompt_lens) if prompt_lens else [prompt_len] * requests
+    num_devices, hbm = _fleet(num_devices)
+    t_setup = time.time()
     model = JaxModel(cfg, params, max_batch=batch,
-                     max_seq=prompt_len + gen_len, attn_impl="flash_jnp")
-    cluster = Cluster(MGBAlg3Scheduler(num_devices),
+                     max_seq=max(lens) + gen_len, attn_impl="flash_jnp")
+    cluster = Cluster(MGBAlg3Scheduler(num_devices, hbm_per_device=hbm),
                       workers=workers or num_devices, shed_late=shed_late,
                       trace=bool(trace_path))
     eng = ServeEngine(cluster, model, max_batch=batch,
                       slo=SLO(ttft_s=ttft_slo_s, tpot_s=tpot_slo_s))
+    setup_s = time.time() - t_setup
     rng = np.random.default_rng(seed)
     t0 = time.time()
-    for _ in range(requests):
+    for n in lens:
         eng.submit(prompt=jnp.asarray(rng.integers(
-            0, cfg.vocab, (1, prompt_len), dtype=np.int32)),
+            0, cfg.vocab, (1, n), dtype=np.int32)),
             gen_len=gen_len)
-    eng.drain()
+    # submission probes each new prompt shape: its compile is set-up
+    submit_s = time.time() - t0
+    if in_flight is not None:
+        in_flight(cluster, eng)
+    t_serve = time.time()
+    eng.drain(timeout_s=900.0)
+    serve_s = time.time() - t_serve
     wall = time.time() - t0
     m = eng.metrics()
     eng.shutdown()
+    cluster.drain()
     cluster.shutdown()
     if trace_path:
         cluster.export_trace(trace_path)
-    m.update(wall_s=wall, tokens_per_s=m["tokens"] / wall,
-             sched_attempts=cluster.stats()["sched_attempts"])
+    m.update(wall_s=wall, setup_s=setup_s, submit_s=submit_s,
+             serve_s=serve_s, tokens_per_s=m["tokens"] / wall,
+             sched_attempts=cluster.stats()["sched_attempts"],
+             engine=eng)
     return m
 
 
@@ -207,7 +258,12 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen-len", type=int, default=32)
-    ap.add_argument("--num-devices", type=int, default=2)
+    ap.add_argument("--num-devices", type=int, default=0,
+                    help="scheduler devices (0 = one per attached device; "
+                         "more is only legal on the CPU backend)")
+    ap.add_argument("--full", action="store_true",
+                    help="published config with bf16 weights (default: "
+                         "the reduced CPU-sized variant)")
     ap.add_argument("--workers", type=int, default=0,
                     help="execution-pool size (0 = one per device)")
     ap.add_argument("--deadline-s", type=float, default=5.0,
@@ -232,13 +288,15 @@ def main():
                          "requests stream individually, decode batches "
                          "grow/shrink per step under scheduler admission")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.continuous:
         res = serve_continuous(
             args.arch, requests=args.requests, batch=args.batch,
             prompt_len=args.prompt_len, gen_len=args.gen_len,
             num_devices=args.num_devices, workers=args.workers,
             ttft_slo_s=args.deadline_s, tpot_slo_s=args.tpot_slo_s,
-            shed_late=args.shed_late, trace_path=args.trace)
+            shed_late=args.shed_late, full=args.full,
+            trace_path=args.trace)
         print(f"[serve --continuous] {res['done']}/{res['requests']} done, "
               f"{res['tokens']} tokens in {res['wall_s']:.1f}s "
               f"({res['tokens_per_s']:.1f} tok/s, "
@@ -253,7 +311,7 @@ def main():
                 prompt_len=args.prompt_len, gen_len=args.gen_len,
                 num_devices=args.num_devices, workers=args.workers,
                 deadline_s=args.deadline_s, shed_late=args.shed_late,
-                preempt=args.preempt, trace_path=args.trace)
+                preempt=args.preempt, full=args.full, trace_path=args.trace)
     print(f"[serve] {res['tokens_generated']} tokens in {res['wall_s']:.1f}s "
           f"({res['tokens_per_s']:.1f} tok/s, "
           f"batch latency {res['mean_batch_latency_s'] * 1e3:.0f} ms, "

@@ -24,6 +24,7 @@ from repro.configs.base import ShapeConfig
 from repro.configs.registry import ARCHS, get_arch
 from repro.data.pipeline import Prefetcher, TokenPipeline, shard_batch
 from repro.dist import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import data_axes, make_mesh
 from repro.models.model import init_params
 from repro.optim import adamw
@@ -116,6 +117,7 @@ def main():
     ap.add_argument("--attn-impl", default="flash",
                     choices=["flash", "flash_jnp", "naive", "pallas"])
     args = ap.parse_args()
+    enable_compile_cache()
     res = train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
                 reduced=not args.full, ckpt_dir=args.ckpt_dir,
                 ckpt_every=args.ckpt_every, resume=args.resume,

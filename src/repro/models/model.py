@@ -283,27 +283,15 @@ def _layer_window(cfg: ArchConfig, layer_idx) -> Any:
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
 
-@jax.custom_vjp
 def _barrier(x):
     """optimization_barrier on the scan carry: without it XLA hoists the
     rms_norm f32 convert of the ENTIRE stacked saved-residual buffer out of
     the backward loop (observed +39 GB/device at gemma2 train_4k).
 
-    custom_vjp because optimization_barrier itself has no differentiation
-    rule (jax <= 0.4.37); the cotangent gets the same barrier so the
-    backward scan carry is protected from the identical hoist."""
+    The barrier's own transpose rule puts the same barrier on the
+    cotangent, so the backward scan carry is protected from the identical
+    hoist without a custom VJP."""
     return jax.lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return _barrier(x), None
-
-
-def _barrier_bwd(_, ct):
-    return (jax.lax.optimization_barrier(ct),)
-
-
-_barrier.defvjp(_barrier_fwd, _barrier_bwd)
 
 
 def _remat(fn, policy: str):

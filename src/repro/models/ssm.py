@@ -165,7 +165,9 @@ def mamba2_apply(p: dict, x: jax.Array, cfg: SSMConfig, *,
     g = jnp.einsum("bcln,bcsn->bcls", c_c, b_c)         # [B,nc,Lc,Lc]
     seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [B,nc,Lc,Lc,nh]
     causal = jnp.tril(jnp.ones((lc, lc), bool))
-    att = jnp.where(causal[None, None, :, :, None], jnp.exp(seg), 0.0) \
+    # mask before exp: above the diagonal seg > 0 and exp overflows over a
+    # long chunk, and a masked inf still turns the gradient into 0 * inf
+    att = jnp.exp(jnp.where(causal[None, None, :, :, None], seg, -jnp.inf)) \
         * g[..., None]
     y_intra = jnp.einsum("bclsh,bcshp->bclhp", att, dtx)
 

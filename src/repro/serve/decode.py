@@ -95,7 +95,11 @@ def greedy_generate(cfg: ArchConfig, params, cache, first_tokens, start_pos,
 
     def body(carry, _):
         tokens, pos, cache = carry
-        logits, cache = serve(params, cache, tokens, pos)
+        logits, new = serve(params, cache, tokens, pos)
+        # the scan carry keeps the cache's dtypes (f32 weights would
+        # otherwise promote a bf16 recurrent state on the first step)
+        cache = jax.tree_util.tree_map(lambda n, o: n.astype(o.dtype),
+                                       new, cache)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (nxt, pos + 1, cache), nxt
 

@@ -89,6 +89,10 @@ class ServeRequest:
     join_epoch: int = 0
     device: Optional[int] = None
     row: Optional[int] = None
+    # the jax device the prefill task was placed on, and the devices its
+    # outputs landed on (live backend; what a placement check compares)
+    prefill_device: Any = None
+    prefill_devices: frozenset = frozenset()
 
     @property
     def ttft_s(self) -> float:
@@ -140,10 +144,10 @@ class NullModel:
                               bytes_accessed=0.0, est_seconds=self.prefill_s,
                               core_demand=2 / SLOTS, bw_demand=2 / SLOTS)
 
-    def prefill(self, req: ServeRequest) -> None:
+    def prefill(self, req: ServeRequest, device: Any = None) -> None:
         req.first_token = 0
 
-    def make_loop_state(self, rows: int) -> Any:
+    def make_loop_state(self, rows: int, device: Any = None) -> Any:
         return None
 
     def adopt(self, state: Any, row: int, req: ServeRequest) -> None:
@@ -160,7 +164,15 @@ class JaxModel:
     Resource vectors are honest: the prefill vector is probed from the
     compiled prefill executable; the per-slot delta is the request's
     KV-cache bytes from ``abstract_cache``; the loop base is the probed
-    full-batch decode footprint minus the rows' share.
+    full-batch decode footprint minus the rows' share. Both probes count
+    the weights as a compiled argument, so every loop and every in-flight
+    prefill reserves one copy of them: pessimistic on one chip, where they
+    share one copy, and exact for a replica on each further chip.
+
+    Placement is honoured: a prefill runs on the device its task was
+    placed on and a decode loop on its own device, each against a replica
+    of the weights made there on first use (no copy where they already
+    live); a prefilled cache moves to the loop that adopts it.
     """
 
     def __init__(self, cfg, params, *, max_batch: int, max_seq: int,
@@ -173,6 +185,8 @@ class JaxModel:
 
         self.cfg = cfg
         self.params = params
+        self._replicas: Dict[Any, Any] = {}
+        self._replica_lock = threading.Lock()
         self.max_batch = max_batch
         self.max_seq = max_seq
         self._jnp = jnp
@@ -195,7 +209,7 @@ class JaxModel:
         full_cache = abstract_cache(cfg, max_batch, max_seq)
         tok_sds = jax.ShapeDtypeStruct((max_batch,), jnp.int32)
         pos_sds = jax.ShapeDtypeStruct((max_batch,), jnp.int32)
-        dvec = probe_fn(_decode, params, full_cache, tok_sds, pos_sds)
+        dvec = probe_fn(self._decode, params, full_cache, tok_sds, pos_sds)
         self.step_vec = dvec
         self.loop_hbm = max(dvec.hbm_bytes - max_batch * self.slot_bytes, 0)
         self.step_seconds = max(dvec.est_seconds, 1e-4)
@@ -218,34 +232,66 @@ class JaxModel:
         from repro.core.probe import probe_fn
         return probe_fn(self._prefill, self.params, {"tokens": req.prompt})
 
-    def prefill(self, req: ServeRequest) -> None:
+    def params_on(self, device: Any) -> Any:
+        """The weights on ``device`` (None: wherever they live now)."""
+        if device is None:
+            return self.params
+        import jax
+        with self._replica_lock:
+            p = self._replicas.get(device)
+            if p is None:
+                p = self._replicas[device] = jax.device_put(self.params,
+                                                            device)
+        return p
+
+    def prefill(self, req: ServeRequest, device: Any = None) -> None:
         import jax
         jnp = self._jnp
-        logits, cache = self._prefill(self.params, {"tokens": req.prompt})
+        prompt = req.prompt if device is None \
+            else jax.device_put(req.prompt, device)
+        logits, cache = self._prefill(self.params_on(device),
+                                      {"tokens": prompt})
         req.first_token = int(jnp.argmax(logits[0]))
-        req.cache = jax.tree_util.tree_map(lambda t: t, cache)
+        req.cache = cache
+        req.prefill_devices = frozenset(
+            d for t in jax.tree_util.tree_leaves((logits, cache))
+            for d in t.devices())
 
-    def make_loop_state(self, rows: int) -> Dict[str, Any]:
+    def make_loop_state(self, rows: int,
+                        device: Any = None) -> Dict[str, Any]:
+        import jax
         import numpy as np
+        with jax.default_device(device):
+            cache = self._D.init_cache(self.cfg, rows, self.max_seq)
+        if device is not None:
+            cache = jax.device_put(cache, device)  # commit: no copy
         return {
-            "cache": self._D.init_cache(self.cfg, rows, self.max_seq),
+            "device": device,
+            "params": self.params_on(device),
+            "cache": cache,
             "tokens": np.zeros((rows,), np.int32),
             "pos": np.zeros((rows,), np.int32),
         }
 
     def adopt(self, state: Dict[str, Any], row: int,
               req: ServeRequest) -> None:
-        state["cache"] = self._insert(state["cache"], req.cache, row)
+        import jax
+        row_cache = req.cache if state["device"] is None \
+            else jax.device_put(req.cache, state["device"])
+        state["cache"] = self._insert(state["cache"], row_cache, row)
         state["tokens"][row] = req.first_token
         state["pos"][row] = req.prompt_len
         req.cache = None  # adopted: the row owns the KV now
 
     def step(self, state: Dict[str, Any],
              rows: List[Optional[ServeRequest]]) -> None:
+        import jax
         jnp = self._jnp
+        put = jnp.asarray if state["device"] is None \
+            else (lambda a: jax.device_put(a, state["device"]))
         logits, state["cache"] = self._decode(
-            self.params, state["cache"],
-            jnp.asarray(state["tokens"]), jnp.asarray(state["pos"]))
+            state["params"], state["cache"],
+            put(state["tokens"]), put(state["pos"]))
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         import numpy as np
         nxt = np.asarray(nxt)
@@ -332,9 +378,10 @@ class ServeEngine:
                     f"device {d} cannot host a decode loop "
                     f"({model.loop_vec(max_batch).hbm_bytes / 1e9:.2f} GB "
                     f"base + {max_batch} rows)")
-            self.loops[d] = _Loop(device=d, host=host,
-                                  rows=[None] * max_batch,
-                                  state=model.make_loop_state(max_batch))
+            self.loops[d] = _Loop(
+                device=d, host=host, rows=[None] * max_batch,
+                state=model.make_loop_state(max_batch,
+                                            cluster.jax_device(d)))
         self._hosts = tuple(lp.host for lp in self.loops.values())
         self._check_capacity()
 
@@ -364,7 +411,8 @@ class ServeEngine:
         job = Job(tasks=[task], name=f"prefill/{req.rid}")
 
         def runner(device, req=req):
-            self.model.prefill(req)
+            req.prefill_device = device
+            self.model.prefill(req, device)
 
         runners = [runner] if self.cluster.backend == "live" else None
         self.cluster.submit(

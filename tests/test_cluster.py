@@ -18,6 +18,7 @@
 import threading
 import time
 
+import pytest
 from _hypothesis_fallback import given, settings, st
 
 from repro.core.cluster import Cluster, JobStatus
@@ -335,6 +336,65 @@ def test_simulator_run_empty_metrics_guarded():
     r2 = Simulator(MGBAlg3Scheduler(2), workers=2).run(
         [Job(tasks=[], name="e")])
     assert r2.completed == 1 and r2.mean_slowdown_pct == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the device table on an accelerator; runner errors
+# ---------------------------------------------------------------------------
+
+class _FakeChip:
+    """Stands in for one attached accelerator: a platform other than the
+    CPU, and the usable HBM it reports."""
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+    def __init__(self, bytes_limit):
+        self._limit = bytes_limit
+
+    def memory_stats(self):
+        return {"bytes_limit": self._limit}
+
+
+@pytest.mark.parametrize("num_devices,hbm_gb,limit_gb,refused", [
+    (2, 8.0, 15.0, True),     # more scheduler devices than chips
+    (1, 16.0, 15.0, True),    # more HBM per device than the chip reports
+    (1, 15.0, 15.0, False),   # one device per chip, at the chip's limit
+])
+def test_live_cluster_device_table_checked_against_chips(
+        monkeypatch, num_devices, hbm_gb, limit_gb, refused):
+    """On an accelerator the live Cluster maps one scheduler device to one
+    chip and refuses a table that promises memory no chip has (on the CPU
+    backend the same tables are virtualized round-robin)."""
+    import jax
+    chip = _FakeChip(int(limit_gb * GB))
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    sched = MGBAlg3Scheduler(num_devices, hbm_per_device=int(hbm_gb * GB))
+    if refused:
+        with pytest.raises(ValueError, match="attached|bytes_limit"):
+            Cluster(sched, workers=1)
+        return
+    c = Cluster(sched, workers=1)
+    assert c.jax_device(0) is chip
+    c.shutdown()
+
+
+def test_runner_exception_surfaces_in_job_error():
+    """A runner's exception is kept on job.error and on the CRASH event, so
+    a compile error or a real RESOURCE_EXHAUSTED is not a bare count."""
+    from repro.obs import events as ev
+
+    def boom(device):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    c = Cluster(MGBAlg3Scheduler(1), workers=1, trace=True)
+    h = c.submit(live_ej("bad", body=boom))
+    h.result(timeout=5.0)
+    c.drain()
+    assert h.status is JobStatus.CRASHED
+    assert "RuntimeError: RESOURCE_EXHAUSTED: out of HBM" in h.job.error
+    crashes = [e for e in c.trace.events() if e.kind == ev.CRASH]
+    assert crashes and crashes[0].data["error"] == h.job.error
+    c.shutdown()
 
 
 # ---------------------------------------------------------------------------

@@ -24,9 +24,10 @@ def _run_in_subprocess(code: str) -> None:
 def test_pipeline_parallel_matches_sequential():
     _run_in_subprocess("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.dist.pipeline import make_pipeline_forward, \\
             stack_stage_params
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         L, d = 8, 32
         w = jax.random.normal(jax.random.PRNGKey(0), (L, d, d)) * 0.1
         def layer_fn(sp, x):
@@ -49,11 +50,12 @@ def test_elastic_reshard_preserves_state():
         from repro.models.model import init_params
         from repro.optim import adamw
         from repro.train.elastic import reshard_state, rescale_batch_size
+        from repro.launch.mesh import make_mesh
         cfg = get_arch("llama3-405b").reduced()
         params = init_params(cfg, jax.random.PRNGKey(0))
         opt = adamw.init_state(adamw.AdamWConfig(), params)
-        mesh_big = jax.make_mesh((4, 2), ("data", "model"))
-        mesh_small = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_big = make_mesh((4, 2), ("data", "model"))
+        mesh_small = make_mesh((2, 2), ("data", "model"))
         p1, o1 = reshard_state(cfg, params, opt, mesh_big)
         p2, o2 = reshard_state(cfg, p1, o1, mesh_small)   # shrink 8 -> 4
         ok = jax.tree_util.tree_all(jax.tree_util.tree_map(
@@ -72,6 +74,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.configs.base import ShapeConfig
         from repro.configs.registry import get_arch
         from repro.dist import sharding as SH
+        from repro.launch.mesh import make_mesh
         from repro.models.model import init_params
         from repro.optim import adamw
         from repro.train.train_step import make_train_step
@@ -85,7 +88,7 @@ def test_sharded_train_step_matches_single_device():
         # unsharded reference
         p1, o1, m1 = jax.jit(step)(params, opt, batch)
         # sharded on (data=4, model=2)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with SH.activation_mesh(mesh):
             psh = SH.to_named(SH.param_specs(cfg, params, mesh), mesh)
             bsh = SH.to_named(SH.batch_specs(cfg, batch, mesh), mesh)

@@ -198,15 +198,31 @@ def test_moe_drops_tokens_over_capacity():
 # sharding rules: divisibility invariant over every arch on a 16x16 mesh
 # ---------------------------------------------------------------------------
 
+def test_mamba2_train_grads_finite_over_a_long_chunk():
+    """The SSD form masks its upper triangle before the exp: over zamba2's
+    published SSM widths and a 128-step chunk the unmasked exp overflows,
+    and a masked inf still made every gradient NaN."""
+    import dataclasses
+    from repro.optim import adamw
+    from repro.train.train_step import make_train_step
+    full = get_arch("zamba2-2.7b")
+    cfg = dataclasses.replace(full.reduced(), ssm=full.ssm)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    opt_cfg = adamw.AdamWConfig()
+    tok = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, cfg.vocab)
+    _, _, m = jax.jit(make_train_step(cfg, opt_cfg))(
+        params, adamw.init_state(opt_cfg, params),
+        {"tokens": tok, "labels": jnp.roll(tok, -1, axis=1)})
+    assert np.isfinite(float(m["grad_norm"])) and \
+        np.isfinite(float(m["loss"]))
+
+
 def test_param_specs_divisibility_all_archs():
     from jax.sharding import AbstractMesh
     from repro.dist.sharding import param_specs
     from repro.optim.adamw import AdamWConfig
     from repro.train.train_step import abstract_train_state
-    try:
-        mesh = AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:  # jax <= 0.4.x: AbstractMesh(((name, size), ...))
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for name, cfg in ARCHS.items():
         params_sds, _ = abstract_train_state(cfg, AdamWConfig())
         specs = param_specs(cfg, params_sds, mesh)

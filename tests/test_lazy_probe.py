@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import lazy
-from repro.core.probe import HBM_BW, PEAK_FLOPS, probe_fn
+from repro.core.probe import probe_fn
 
 
 def test_lazy_buffer_records_without_allocation():
