@@ -54,6 +54,7 @@ from repro.core.scheduler.base import DEADLINE_SHED, DEFAULT_HBM, Scheduler
 from repro.core.task import Job, Task
 from repro.core.topology import placement_devices
 from repro.obs import events as obs
+from repro.obs.spans import span
 
 
 class OOMError(RuntimeError):
@@ -80,6 +81,10 @@ class ExecRecord:
     # size of the reserved device group (1 for single-chip tasks); the gang
     # bench groups queueing-delay percentiles by this
     gang_chips: int = 1
+    # when the scheduler's admission callback handed the task to the
+    # execution pool (-1: never admitted): t_admit - t_queue is the wait in
+    # the scheduler, t_start - t_admit the wait for a pool worker
+    t_admit: float = -1.0
 
     @property
     def started(self) -> bool:
@@ -184,6 +189,7 @@ class _Ready:
     task_idx: int
     placement: object
     epoch: int
+    t_admit: float
 
 
 class Executor:
@@ -457,7 +463,8 @@ class Executor:
                     time.monotonic(), crashed=True))
                 self._finish(jr, crashed=True)
                 return
-            self._ready.put(_Ready(jr, idx, placement, epoch))
+            self._ready.put(_Ready(jr, idx, placement, epoch,
+                                   time.monotonic()))
 
         self.sched.admit_or_enqueue(task, on_admit)
 
@@ -494,7 +501,8 @@ class Executor:
                         data={"reason": "oom"})
             self._record(jr, ExecRecord(
                 jr.ej.job.name, task.name, lead, jr.t_queue, NEVER_STARTED,
-                time.monotonic(), crashed=True, gang_chips=len(devs)))
+                time.monotonic(), crashed=True, gang_chips=len(devs),
+                t_admit=item.t_admit))
             self._finish(jr, crashed=True)
             return
         # serialize with any still-running superseded attempt of this task,
@@ -539,7 +547,8 @@ class Executor:
                                                self.device_map[lead])
                     bound = (self.device_map[lead] if len(devs) == 1
                              else [self.device_map[d] for d in devs])
-                    jr.ej.runners[item.task_idx](bound)
+                    with span("repro.exec.run", task=task.name):
+                        jr.ej.runners[item.task_idx](bound)
                 except Exception as e:
                     # keep the cause: a compile error or a real
                     # RESOURCE_EXHAUSTED must not read as a bare crash count
@@ -568,12 +577,13 @@ class Executor:
             now = time.monotonic()
             self._record(jr, ExecRecord(
                 jr.ej.job.name, task.name, lead, jr.t_queue,
-                t_start, now, crashed=True, gang_chips=len(devs)))
+                t_start, now, crashed=True, gang_chips=len(devs),
+                t_admit=item.t_admit))
             self._finish(jr, crashed=True)
             return
         self._record(jr, ExecRecord(
             jr.ej.job.name, task.name, lead, jr.t_queue, t_start,
-            time.monotonic(), gang_chips=len(devs)))
+            time.monotonic(), gang_chips=len(devs), t_admit=item.t_admit))
         jr.next_task += 1
         if jr.next_task >= len(jr.ej.job.tasks):
             self._finish(jr, crashed=False)

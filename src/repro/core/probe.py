@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.chips import device_peaks
 from repro.core.task import ResourceVector
+from repro.obs.spans import span
 
 
 def _mem_bytes(compiled) -> int:
@@ -117,16 +118,19 @@ def probe_fn(fn: Callable, *args, chips: int = 1, work_scale: float = 1.0,
     lowered as it stands, so its donations (aliased outputs) count as they
     will when it runs.
     """
-    sds = _abstractify(args)
-    leaves, treedef = jax.tree_util.tree_flatten(sds)
-    key = (id(fn), treedef,
-           tuple((tuple(l.shape), str(l.dtype)) for l in leaves))
-    compiled = _probe_cache.get(key)
-    if compiled is None:
-        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-        compiled = jitted.lower(*sds).compile()
-        if len(_probe_cache) < 512:
-            _probe_cache[key] = compiled
-    return vector_from_compiled(compiled, chips=chips, work_scale=work_scale,
-                                flops_override=flops_override,
-                                efficiency=efficiency)
+    with span("repro.probe") as sp:
+        sds = _abstractify(args)
+        leaves, treedef = jax.tree_util.tree_flatten(sds)
+        key = (id(fn), treedef,
+               tuple((tuple(l.shape), str(l.dtype)) for l in leaves))
+        compiled = _probe_cache.get(key)
+        sp.set_metadata(hit=int(compiled is not None))
+        if compiled is None:
+            jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+            compiled = jitted.lower(*sds).compile()
+            if len(_probe_cache) < 512:
+                _probe_cache[key] = compiled
+        return vector_from_compiled(compiled, chips=chips,
+                                    work_scale=work_scale,
+                                    flops_override=flops_override,
+                                    efficiency=efficiency)

@@ -65,6 +65,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 from repro.core.task import Task, observed_highwater
 from repro.obs import events as obs
 from repro.obs import explain as obsx
+from repro.obs.spans import span
 
 # 16 GB v5e HBM per chip (the paper's P100/V100 also had 16 GB)
 DEFAULT_HBM = 16 * 1024**3
@@ -446,6 +447,10 @@ class WaiterQueueMixin:
         task can NEVER be admitted, the callback fires once with
         ``placement=None`` — the caller must give up, not retry. Returns True
         iff admitted immediately."""
+        with span("repro.sched.admit"):
+            return self._admit_or_enqueue(task, callback)
+
+    def _admit_or_enqueue(self, task: Task, callback: AdmitCallback) -> bool:
         fired: List[Tuple[_Waiter, Any, int]] = []
         with self._lock:
             placement = self._admit_locked(task)
@@ -1185,6 +1190,10 @@ class Scheduler(WaiterQueueMixin):
         waiters that device can't satisfy. With ``epoch``, a completion from
         an evicted (superseded) run is fenced: nothing is released and False
         is returned."""
+        with span("repro.sched.end"):
+            return self._task_end(task, epoch)
+
+    def _task_end(self, task: Task, epoch: Optional[int]) -> bool:
         with self._lock:
             if self._stale_locked(task, epoch):
                 return False
@@ -1252,8 +1261,9 @@ class Scheduler(WaiterQueueMixin):
         memory-hard guarantee covers batch growth. Returns True iff grown
         immediately; otherwise ``callback`` fires on a later drain (or with
         DEADLINE_SHED / None, exactly like ``admit_or_enqueue``)."""
-        slot_task.grow_hosts = tuple(hosts)
-        return self.admit_or_enqueue(slot_task, callback)
+        with span("repro.sched.grow"):
+            slot_task.grow_hosts = tuple(hosts)
+            return self.admit_or_enqueue(slot_task, callback)
 
     def task_shrink(self, slot_task: Task, *,
                     epoch: Optional[int] = None) -> bool:
@@ -1261,7 +1271,8 @@ class Scheduler(WaiterQueueMixin):
         ``task_end`` (same epoch fencing, same freed-capacity drain hint) —
         named so call sites read as batch shrink, and so the symmetry
         grow/shrink ↔ begin/end is explicit."""
-        return self.task_end(slot_task, epoch=epoch)
+        with span("repro.sched.shrink"):
+            return self.task_end(slot_task, epoch=epoch)
 
     # -- fault tolerance -----------------------------------------------------
     def mark_dead(self, device_index: int) -> List[Task]:

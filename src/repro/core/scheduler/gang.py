@@ -48,6 +48,7 @@ from repro.core.topology import (
 )
 from repro.obs import events as obs
 from repro.obs import explain as obsx
+from repro.obs.spans import span
 
 CellOrIndex = Union[Cell, int]
 
@@ -342,6 +343,10 @@ class GangScheduler(WaiterQueueMixin):
         """Release the WHOLE reservation (chips + links) and re-drive the
         waiter queue, hinting the drain with the freed cells so waiters no
         freed cell can satisfy are skipped without a probe."""
+        with span("repro.sched.end"):
+            return self._task_end(task, epoch)
+
+    def _task_end(self, task: Task, epoch: Optional[int]) -> bool:
         with self._lock:
             if self._stale_locked(task, epoch):
                 return False

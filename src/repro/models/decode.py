@@ -80,12 +80,20 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
     batching — see serve.engine).
 
     Returns (logits [B, V] f32, updated cache).
+
+    Named scopes mark the model's parts in the compiled program's op_name
+    metadata (and so in a device trace): ``embed``, ``norm``, ``mamba``,
+    ``state_write`` (the new recurrent state), ``attn``, ``kv_write``,
+    ``mlp``, ``logits``. Outside them lies the layer scans' own work:
+    slicing the stacked weights and caches, stacking the new ones, copies
+    and loop bookkeeping.
     """
     from repro.dist.sharding import constrain
-    x = params["embed"][tokens]  # [B, d]
-    x = constrain(x, "batch", None)
-    if cfg.name.startswith("gemma2"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # [B, d]
+        x = constrain(x, "batch", None)
+        if cfg.name.startswith("gemma2"):
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     ring = uses_ring(cfg)
 
     if cfg.family == "ssm":
@@ -93,9 +101,10 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
             lp, conv, ssm_state = xs
             conv = jax.lax.optimization_barrier(conv)
             ssm_state = jax.lax.optimization_barrier(ssm_state)
-            y, new = SSM.mamba1_decode_step(
-                lp["mamba"], L.rms_norm(h, lp["norm"]),
-                {"conv": conv, "ssm": ssm_state}, cfg.ssm)
+            with jax.named_scope("mamba"):
+                y, new = SSM.mamba1_decode_step(
+                    lp["mamba"], L.rms_norm(h, lp["norm"]),
+                    {"conv": conv, "ssm": ssm_state}, cfg.ssm)
             return h + y, (new["conv"], new["ssm"])
         x, (conv, ssm_state) = jax.lax.scan(
             body, x, (params["layers"], cache["conv"], cache["ssm"]))
@@ -110,17 +119,19 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
 
             def mamba_body(hh, ys):
                 mp, conv, st = ys
-                y, new = SSM.mamba2_decode_step(
-                    mp["mamba"], L.rms_norm(hh, mp["norm_m"]),
-                    {"conv": conv, "ssm": st}, cfg.ssm)
+                with jax.named_scope("mamba"):
+                    y, new = SSM.mamba2_decode_step(
+                        mp["mamba"], L.rms_norm(hh, mp["norm_m"]),
+                        {"conv": conv, "ssm": st}, cfg.ssm)
                 return hh + y, (new["conv"], new["ssm"])
             h, (mconv, mssm) = jax.lax.scan(
                 mamba_body, h,
                 ({"mamba": gp["mamba"], "norm_m": gp["norm_m"]}, mconv, mssm))
-            a, (kc, vc) = attn_decode_block(
-                shared["attn"], L.rms_norm(h, gp["norm_attn"])[:, None], cfg,
-                pos=pos, kcache=kc, vcache=vc, window=cfg.sliding_window,
-                ring=ring)
+            with jax.named_scope("attn"):
+                a, (kc, vc) = attn_decode_block(
+                    shared["attn"], L.rms_norm(h, gp["norm_attn"])[:, None],
+                    cfg, pos=pos, kcache=kc, vcache=vc,
+                    window=cfg.sliding_window, ring=ring)
             h = h + a[:, 0]
             m = L.mlp_apply(shared["mlp"], L.rms_norm(h, gp["norm_mlp"]),
                             cfg.mlp_act)
@@ -147,10 +158,11 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
             kc = jax.lax.optimization_barrier(kc)
             vc = jax.lax.optimization_barrier(vc)
             window = _layer_window(cfg, idx)
-            a, kv = attn_decode_block(
-                lp["attn"], L.rms_norm(h, lp["norm1"])[:, None], cfg,
-                pos=pos, kcache=kc, vcache=vc, kscale=ks, vscale=vs,
-                window=window, ring=ring)
+            with jax.named_scope("attn"):
+                a, kv = attn_decode_block(
+                    lp["attn"], L.rms_norm(h, lp["norm1"])[:, None], cfg,
+                    pos=pos, kcache=kc, vcache=vc, kscale=ks, vscale=vs,
+                    window=window, ring=ring)
             h = h + a[:, 0]
             hn = L.rms_norm(h, lp["norm2"])[:, None]
             if cfg.moe is not None:
@@ -170,7 +182,8 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
             new_cache = {"k": kc, "v": vc}
 
     x = L.rms_norm(x, params["final_norm"])
-    logits = logits_from_hidden(cfg, params, x[:, None])[:, 0]
+    with jax.named_scope("logits"):
+        logits = logits_from_hidden(cfg, params, x[:, None])[:, 0]
     return logits, new_cache
 
 

@@ -21,11 +21,12 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    out = x * jax.lax.rsqrt(var + eps) * (1.0 + scale.astype(jnp.float32))
-    return out.astype(dtype)
+    with jax.named_scope("norm"):
+        dtype = x.dtype
+        x = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        out = x * jax.lax.rsqrt(var + eps) * (1.0 + scale.astype(jnp.float32))
+        return out.astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -417,13 +418,14 @@ def mlp_apply(p: dict, x: jax.Array, act: str) -> jax.Array:
     """
     from repro.dist.sharding import constrain
     pin = (("batch",) + (None,) * (x.ndim - 2) + ("model",))
-    if act == "silu_gated":
-        h = jax.nn.silu(x @ p["wi"]) * (x @ p["wg"])
-    elif act == "gelu_gated":
-        h = jax.nn.gelu(x @ p["wi"]) * (x @ p["wg"])
-    elif act == "squared_relu":
-        h = jnp.square(jax.nn.relu(x @ p["wi"]))
-    else:
-        raise ValueError(f"unknown mlp act {act!r}")
-    h = constrain(h, *pin)
-    return h @ p["wo"]
+    with jax.named_scope("mlp"):
+        if act == "silu_gated":
+            h = jax.nn.silu(x @ p["wi"]) * (x @ p["wg"])
+        elif act == "gelu_gated":
+            h = jax.nn.gelu(x @ p["wi"]) * (x @ p["wg"])
+        elif act == "squared_relu":
+            h = jnp.square(jax.nn.relu(x @ p["wi"]))
+        else:
+            raise ValueError(f"unknown mlp act {act!r}")
+        h = constrain(h, *pin)
+        return h @ p["wo"]

@@ -238,11 +238,13 @@ def attn_decode_block(p: Params, x: jax.Array, cfg: ArchConfig, *, pos,
 
         def write(cache, new):  # new: [B, H, 1, D] or [B, H, 1] (scales)
             mask = oh[:, None, :, None] if cache.ndim == 4 else oh[:, None, :]
-            return jnp.where(mask, new.astype(cache.dtype), cache)
+            with jax.named_scope("kv_write"):
+                return jnp.where(mask, new.astype(cache.dtype), cache)
     else:
         def write(cache, new):
-            return jax.lax.dynamic_update_slice_in_dim(
-                cache, new.astype(cache.dtype), slot[0], axis=2)
+            with jax.named_scope("kv_write"):
+                return jax.lax.dynamic_update_slice_in_dim(
+                    cache, new.astype(cache.dtype), slot[0], axis=2)
 
     if kscale is not None:
         k_q, k_s = L.quantize_kv(k, kscale.dtype)

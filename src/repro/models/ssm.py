@@ -35,7 +35,8 @@ def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
 def conv1d_step(x_t: jax.Array, conv_state: jax.Array, w: jax.Array,
                 b: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """One decode step. x_t: [B, C]; conv_state: [B, W-1, C]."""
-    window = jnp.concatenate([conv_state, x_t[:, None]], axis=1)  # [B, W, C]
+    with jax.named_scope("state_write"):
+        window = jnp.concatenate([conv_state, x_t[:, None]], axis=1)  # [B,W,C]
     out = jnp.einsum("bwc,cw->bc", window, w) + b
     return out, window[:, 1:]
 
@@ -116,7 +117,8 @@ def mamba1_decode_step(p: dict, x_t: jax.Array, state: dict, cfg: SSMConfig):
     a_cont = -jnp.exp(p["A_log"].astype(jnp.float32))
     a = jnp.exp(dt[..., None].astype(jnp.float32) * a_cont)  # [B,e,n]
     b = (dt * xs)[..., None].astype(jnp.float32) * bmat[:, None, :].astype(jnp.float32)
-    h = a * state["ssm"] + b
+    with jax.named_scope("state_write"):
+        h = a * state["ssm"] + b
     y = jnp.einsum("ben,bn->be", h, cmat.astype(jnp.float32))
     y = (y + xs.astype(jnp.float32) * p["D"]) \
         * jax.nn.silu(z).astype(jnp.float32)
@@ -209,8 +211,9 @@ def mamba2_decode_step(p: dict, x_t: jax.Array, state: dict, cfg: SSMConfig):
     dt32 = dt.astype(jnp.float32)
     a = jnp.exp(-jnp.exp(p["A_log"].astype(jnp.float32)) * dt32)  # [B,nh]
     dtx = dt32[..., None] * xh                                    # [B,nh,P]
-    h = state["ssm"] * a[..., None, None] \
-        + dtx[..., None] * bmat.astype(jnp.float32)[:, None, None, :]
+    with jax.named_scope("state_write"):
+        h = state["ssm"] * a[..., None, None] \
+            + dtx[..., None] * bmat.astype(jnp.float32)[:, None, None, :]
     y = jnp.einsum("bhpn,bn->bhp", h, cmat.astype(jnp.float32))
     y = y + p["D"][:, None] * xh
     y = y.reshape(bsz, e).astype(x_t.dtype)

@@ -26,6 +26,13 @@ backend's own timeline (wall monotonic live, virtual clock simulated).
     text exposition
   * ``obs.whatif``  — counterfactual replay of a recorded trace under
     alternate scheduler policies, with decision-level divergence diffs
+  * ``obs.spans``   — ``span(name, **args)``: timing spans at the work
+    sites (submit, probe, admission, dispatch, the decode pump's phases,
+    garbage collection) as profiler annotations, on the device trace's
+    clock; no-ops unless a ``jax.profiler`` session collects
+
+The split: spans time the work on the device's clock; the ``Tracer``
+logs the scheduler's decisions on the backend's own timeline.
 
 The subsystem imports nothing from ``repro.core`` at module load so the
 scheduler base can import it without cycles (``obs.whatif`` imports the
@@ -35,7 +42,7 @@ budget survives tracing disabled).
 """
 from repro.obs import (  # noqa: F401
     calibrate, events, explain, export, metrics, profile, replay, slo,
-    whatif,
+    spans, whatif,
 )
 from repro.obs.calibrate import (  # noqa: F401
     CalibratedScheduler, CalibrationStore, attach_calibrator,
@@ -48,3 +55,4 @@ from repro.obs.profile import (  # noqa: F401
     Profiler, TaskProfile, device_occupancy, format_profile,
     profiles_from_events,
 )
+from repro.obs.spans import span  # noqa: F401

@@ -19,8 +19,8 @@ state with alert callbacks, instead of a post-hoc notebook:
     duration exceeds roofline x (1 + envelope) is a violation.
   * ``SLOMonitor.for_serving`` subscribes the monitor to a
     ``MetricsRegistry``'s ``ttft_s`` / ``tpot_s`` histograms via the
-    registry's ``on_record`` observer hook — the serve engine's existing
-    metric writes feed the monitor with no new instrumentation.
+    registry's ``on_record`` observer hook: whatever records serving
+    latencies there feeds the monitor with no new instrumentation.
   * ``prometheus_text`` renders a registry snapshot (and optionally a
     monitor's status) in the Prometheus text exposition format, so a
     scrape endpoint is one ``web.Response(text=...)`` away.

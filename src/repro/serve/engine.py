@@ -46,7 +46,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 from repro.core.cluster import Cluster, JobHandle, JobStatus
 from repro.core.scheduler.base import DEADLINE_SHED, SLOTS, Scheduler
 from repro.core.task import Job, ResourceVector, Task, UnitTask
-from repro.obs.metrics import MetricsRegistry
+from repro.obs.spans import span
 
 _rids = itertools.count()
 
@@ -286,15 +286,17 @@ class JaxModel:
     def step(self, state: Dict[str, Any],
              rows: List[Optional[ServeRequest]]) -> None:
         import jax
+        import numpy as np
         jnp = self._jnp
         put = jnp.asarray if state["device"] is None \
             else (lambda a: jax.device_put(a, state["device"]))
-        logits, state["cache"] = self._decode(
-            state["params"], state["cache"],
-            put(state["tokens"]), put(state["pos"]))
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        import numpy as np
-        nxt = np.asarray(nxt)
+        with span("repro.serve.step"):
+            logits, state["cache"] = self._decode(
+                state["params"], state["cache"],
+                put(state["tokens"]), put(state["pos"]))
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with span("repro.serve.readback"):
+            nxt = np.asarray(nxt)
         for row, req in enumerate(rows):
             if req is None:
                 continue
@@ -343,14 +345,10 @@ class ServeEngine:
 
     def __init__(self, cluster: Cluster, model, *, max_batch: int = 8,
                  slo: SLO = SLO(), loop_devices: Optional[Sequence[int]] = None,
-                 prefill_priority: int = 10, decode_priority: int = 5,
-                 metrics_registry: Optional[MetricsRegistry] = None):
+                 prefill_priority: int = 10, decode_priority: int = 5):
         if max_batch < 1 or max_batch >= SLOTS:
             raise ValueError(f"max_batch must be in [1, {SLOTS - 1}]")
         self.cluster = cluster
-        # optional obs.metrics sink: per-request ttft_s/tpot_s histograms
-        # recorded as requests resolve (streaming — no end-of-run scan)
-        self.metrics_registry = metrics_registry
         self.sched: Scheduler = cluster.sched
         self.model = model
         self.max_batch = max_batch
@@ -402,24 +400,26 @@ class ServeEngine:
                            prompt=prompt)
         with self._lock:
             self.requests.append(req)
-        vec = self.model.prefill_vec(req)
-        task = Task(units=[UnitTask(fn=None,
-                                    memobjs=frozenset({f"req/{req.rid}"}),
-                                    resources=vec,
-                                    name=f"prefill/{req.rid}")],
-                    name=f"prefill/{req.rid}")
-        job = Job(tasks=[task], name=f"prefill/{req.rid}")
 
         def runner(device, req=req):
             req.prefill_device = device
             self.model.prefill(req, device)
 
         runners = [runner] if self.cluster.backend == "live" else None
-        self.cluster.submit(
-            job, runners=runners, priority=self.prefill_priority,
-            deadline_s=deadline_s if deadline_s is not None
-            else self.slo.ttft_s,
-            on_done=lambda h, req=req: self._on_prefill_done(req, h))
+        with span("repro.serve.submit", rid=req.rid,
+                  prompt_len=req.prompt_len):
+            vec = self.model.prefill_vec(req)
+            name = f"prefill/{req.rid}"
+            task = Task(units=[UnitTask(fn=None,
+                                        memobjs=frozenset({f"req/{req.rid}"}),
+                                        resources=vec, name=name)],
+                        name=name)
+            self.cluster.submit(
+                Job(tasks=[task], name=name), runners=runners,
+                priority=self.prefill_priority,
+                deadline_s=deadline_s if deadline_s is not None
+                else self.slo.ttft_s,
+                on_done=lambda h, req=req: self._on_prefill_done(req, h))
         return req
 
     def _on_prefill_done(self, req: ServeRequest, handle: JobHandle) -> None:
@@ -433,9 +433,6 @@ class ServeEngine:
             return
         req.t_first = self.cluster.now
         req.n_tokens = 1
-        if self.metrics_registry is not None:
-            self.metrics_registry.hist("ttft_s").record(
-                req.t_first - req.arrival_t)
         if req.first_token is not None:
             req.tokens.append(req.first_token)
         if req.gen_len <= 1:
@@ -498,14 +495,24 @@ class ServeEngine:
             loop.rows[row] = req
             req.row = row
             req.status = RequestStatus.DECODING
-            self.model.adopt(loop.state, row, req)
+            with span("repro.serve.adopt", rid=req.rid):
+                self.model.adopt(loop.state, row, req)
 
     def pump(self) -> int:
         """Advance every decode loop one step: adopt admitted joins, decode
         one token per active row, retire finished rows (``task_shrink`` —
         which re-drives parked joins/prefills). Returns the number of tokens
         emitted."""
-        emitted = 0
+        with span("repro.serve.pump") as sp:
+            emitted, kv = self._pump()
+            # each row stepped emits one token
+            sp.set_metadata(rows=emitted, kv=kv, emitted=emitted)
+        return emitted
+
+    def _pump(self) -> Tuple[int, int]:
+        """``pump``'s work: (tokens emitted, the sum of the positions the
+        stepped rows decoded at)."""
+        emitted = kv = 0
         retired: List[ServeRequest] = []
         # per-decode-step TPOT attribution: observed inter-step gap vs the
         # model's predicted step_seconds, fed to an attached calibration
@@ -526,13 +533,11 @@ class ServeEngine:
                     obs_step = now - loop.last_step_t
                     if store is not None:
                         store.note_step(loop.device, pred_step, obs_step)
-                    if self.metrics_registry is not None:
-                        self.metrics_registry.hist("decode_step_s").record(
-                            obs_step)
                 loop.last_step_t = now
                 for row, req in enumerate(loop.rows):
                     if req is None:
                         continue
+                    kv += req.prompt_len + req.n_tokens - 1
                     req.n_tokens += 1
                     emitted += 1
                     if req.n_tokens >= req.gen_len:
@@ -540,18 +545,16 @@ class ServeEngine:
                         req.row = None
                         req.t_done = now
                         req.status = RequestStatus.DONE
-                        if self.metrics_registry is not None \
-                                and req.n_tokens > 1:
-                            self.metrics_registry.hist("tpot_s").record(
-                                req.tpot_s)
                         retired.append(req)
-        for req in retired:
+        if retired:
             # outside the engine lock: the shrink's drain fires join
             # callbacks inline, which re-enter the engine
-            self.sched.task_shrink(req.slot_task, epoch=req.join_epoch)
-        if retired:
+            with span("repro.serve.retire", n=len(retired)):
+                for req in retired:
+                    self.sched.task_shrink(req.slot_task,
+                                           epoch=req.join_epoch)
             self._check_capacity()
-        return emitted
+        return emitted, kv
 
     # -- drivers ------------------------------------------------------------
     def run_until(self, t: float) -> None:
