@@ -72,6 +72,16 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _put(stack: jax.Array, idx, value: jax.Array) -> jax.Array:
+    """``stack`` with ``value`` written in place at the leading index
+    ``idx``: a dynamic_update_slice (``.at[idx].set`` lowers to a
+    scatter)."""
+    value = value.reshape((1,) * len(idx) + value.shape)
+    start = tuple(idx) + (0,) * (value.ndim - len(idx))
+    return jax.lax.dynamic_update_slice(stack, value.astype(stack.dtype),
+                                        start)
+
+
 def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
                 tokens: jax.Array, pos: jax.Array
                 ) -> Tuple[jax.Array, Cache]:
@@ -86,7 +96,9 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
     ``state_write`` (the new recurrent state), ``attn``, ``kv_write``,
     ``mlp``, ``logits``. Outside them lies the layer scans' own work:
     slicing the stacked weights and caches, stacking the new ones, copies
-    and loop bookkeeping.
+    and loop bookkeeping. The hybrid step slices and stacks nothing: its
+    reads fuse into the model's ops, and each new state or KV is written
+    into its slot under ``state_write`` or ``kv_write``.
     """
     from repro.dist.sharding import constrain
     with jax.named_scope("embed"):
@@ -110,36 +122,46 @@ def decode_step(params: Params, cfg: ArchConfig, cache: Cache,
             body, x, (params["layers"], cache["conv"], cache["ssm"]))
         new_cache = {"conv": conv, "ssm": ssm_state}
     elif cfg.family == "hybrid":
+        # The scan runs over the group index. Weights are read in place from
+        # their stacks; states and caches ride in the carry and are updated
+        # in place, under state_write / kv_write since XLA fuses the new
+        # state's math into the update. Scanned as xs/ys instead, each
+        # group is sliced out of its stack and copied to another layout for
+        # the dots, every step. A group's Mamba layers are unrolled: a
+        # rolled inner loop reading [g, j] copies the whole in_proj stack
+        # to another layout once per step.
         shared = params["shared"]
+        gps = params["groups"]
 
-        def group_body(h, xs):
-            gp, mconv, mssm, kc, vc = xs
-            kc = jax.lax.optimization_barrier(kc)
-            vc = jax.lax.optimization_barrier(vc)
-
-            def mamba_body(hh, ys):
-                mp, conv, st = ys
+        def group_body(carry, g):
+            h, mconv, mssm, kc, vc = carry
+            for j in range(cfg.hybrid_shared_every - 1):
+                mp = jax.tree_util.tree_map(lambda t: t[g, j], gps["mamba"])
                 with jax.named_scope("mamba"):
                     y, new = SSM.mamba2_decode_step(
-                        mp["mamba"], L.rms_norm(hh, mp["norm_m"]),
-                        {"conv": conv, "ssm": st}, cfg.ssm)
-                return hh + y, (new["conv"], new["ssm"])
-            h, (mconv, mssm) = jax.lax.scan(
-                mamba_body, h,
-                ({"mamba": gp["mamba"], "norm_m": gp["norm_m"]}, mconv, mssm))
+                        mp, L.rms_norm(h, gps["norm_m"][g, j]),
+                        {"conv": mconv[g, j], "ssm": mssm[g, j]}, cfg.ssm)
+                    with jax.named_scope("state_write"):
+                        mconv = _put(mconv, (g, j), new["conv"])
+                        mssm = _put(mssm, (g, j), new["ssm"])
+                h = h + y
             with jax.named_scope("attn"):
-                a, (kc, vc) = attn_decode_block(
-                    shared["attn"], L.rms_norm(h, gp["norm_attn"])[:, None],
-                    cfg, pos=pos, kcache=kc, vcache=vc,
+                a, (kg, vg) = attn_decode_block(
+                    shared["attn"],
+                    L.rms_norm(h, gps["norm_attn"][g])[:, None], cfg,
+                    pos=pos, kcache=kc[g], vcache=vc[g],
                     window=cfg.sliding_window, ring=ring)
+                with jax.named_scope("kv_write"):
+                    kc = _put(kc, (g,), kg)
+                    vc = _put(vc, (g,), vg)
             h = h + a[:, 0]
-            m = L.mlp_apply(shared["mlp"], L.rms_norm(h, gp["norm_mlp"]),
+            m = L.mlp_apply(shared["mlp"], L.rms_norm(h, gps["norm_mlp"][g]),
                             cfg.mlp_act)
-            return h + m, (mconv, mssm, kc, vc)
-        x, (mconv, mssm, kc, vc) = jax.lax.scan(
-            group_body, x,
-            (params["groups"], cache["m_conv"], cache["m_ssm"],
-             cache["k"], cache["v"]))
+            return (h + m, mconv, mssm, kc, vc), None
+        (x, mconv, mssm, kc, vc), _ = jax.lax.scan(
+            group_body,
+            (x, cache["m_conv"], cache["m_ssm"], cache["k"], cache["v"]),
+            jnp.arange(cfg.n_layers // cfg.hybrid_shared_every))
         new_cache = {"m_conv": mconv, "m_ssm": mssm, "k": kc, "v": vc}
     else:
         layer_idx = jnp.arange(cfg.n_layers)
