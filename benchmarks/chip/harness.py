@@ -27,6 +27,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 if HERE not in sys.path:
     sys.path.insert(0, HERE)
 
+import arch  # noqa: E402
 import traffic as TR  # noqa: E402
 import weights as W  # noqa: E402
 
@@ -76,30 +77,25 @@ class TrainJob:
 
 def program_config(cfg: dict):
     """The program's ArchConfig for a configuration file: its registered
-    architecture with the file's sizes. A size the file states that differs
-    from the registered one must be listed in ``reduced``."""
-    from repro.configs.base import SSMConfig
+    architecture with the file's sizes, and the fields the architecture's
+    file sets (``program_fields``). A value that differs from the
+    registered one must be listed in ``reduced``."""
     from repro.configs.registry import get_arch
     base = get_arch(cfg["program_arch"])
     keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-            "d_ff", "vocab", "hybrid_shared_every")
+            "d_ff", "vocab")
     def value(k):       # the registered value, a head size resolved
         return base.resolved_head_dim if k == "head_dim" and base.n_heads \
             else getattr(base, k)
-    kw = {k: cfg[k] for k in keys if k in cfg and cfg[k] != value(k)}
-    if "ssm" in cfg:
-        s = cfg["ssm"]
-        kw["ssm"] = SSMConfig(state_dim=s["state_dim"],
-                              conv_width=s["conv_width"], expand=s["expand"],
-                              headdim=s.get("headdim", base.ssm.headdim),
-                              chunk=s.get("chunk", base.ssm.chunk))
-    pc = dataclasses.replace(base, **kw)
+    fields = {k: cfg[k] for k in keys if k in cfg}
+    fields.update(arch.module(cfg).program_fields(cfg, base))
+    kw = {k: v for k, v in fields.items() if v != value(k)}
     for k, v in kw.items():
-        if k not in cfg.get("reduced", []) and value(k) != v:
+        if k not in cfg.get("reduced", []):
             raise ValueError(f"{cfg['name']}: {k}={v} differs from the "
                              f"program's {value(k)} and is not in "
                              "'reduced'")
-    return pc
+    return dataclasses.replace(base, **kw)
 
 
 def check_layout(cfg: dict, pc) -> None:

@@ -1,7 +1,9 @@
-"""Plain float32 reference of the served architecture, in ``jax.numpy``.
+"""Plain float32 reference, in ``jax.numpy``: the blocks, and the output
+head and training on top of an architecture's ``hidden``.
 
-It follows the equations of the architecture as the configuration file
-states them (its ``departures`` list says where that differs from the
+Each architecture's file (``archs/<program_arch>.py``) composes these
+blocks into its ``hidden`` as the configuration file states the
+architecture (its ``departures`` list says where that differs from the
 published model), with no kernels, cache, chunking or batching tricks:
 
   * Mamba-2 (SSD) in its quadratic form: y_t = sum_{s<=t} (C_t . B_s)
@@ -23,6 +25,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+import arch
 
 F32 = jnp.float32
 
@@ -123,41 +127,18 @@ def mamba2(p, x, ssm, eps, quant):
     return y @ _w(p["out_proj"], quant)
 
 
-def hidden(params, cfg, tokens, quant=False):
-    """Final-normed hidden states [B, S, d] for ``tokens`` [B, S]."""
-    eps = cfg["norm_eps"]
-    x = params["embed"][tokens].astype(F32)
-    if cfg["family"] == "hybrid":
-        shared = params["shared"]
-
-        def group(h, gp):
-            def layer(hh, mp):
-                return hh + mamba2(mp["mamba"], rms_norm(hh, mp["norm_m"], eps),
-                                   cfg["ssm"], eps, quant), None
-            h, _ = jax.lax.scan(layer, h, {"mamba": gp["mamba"],
-                                          "norm_m": gp["norm_m"]})
-            h = h + attention(shared["attn"], rms_norm(h, gp["norm_attn"], eps),
-                              cfg, quant)
-            h = h + gated_mlp(shared["mlp"], rms_norm(h, gp["norm_mlp"], eps),
-                              quant)
-            return h, None
-        x, _ = jax.lax.scan(group, x, params["groups"])
-    else:
-        raise ValueError(cfg["family"])
-    return rms_norm(x, params["final_norm"], eps)
-
-
 def logits(params, cfg, tokens, quant=False):
     """Logits [B, S, V] at every position of ``tokens`` [B, S]."""
     with jax.default_matmul_precision("highest"):
-        return hidden(params, cfg, tokens, quant) \
+        return arch.module(cfg).hidden(params, cfg, tokens, quant) \
             @ _w(params["lm_head"], quant)
 
 
 def loss(params, cfg, tokens, labels, quant=False):
     """Mean next-token cross-entropy over every position."""
     with jax.default_matmul_precision("highest"):
-        lg = hidden(params, cfg, tokens, quant) @ _w(params["lm_head"], quant)
+        lg = arch.module(cfg).hidden(params, cfg, tokens, quant) \
+            @ _w(params["lm_head"], quant)
         lse = jax.nn.logsumexp(lg, -1)
         gold = jnp.take_along_axis(lg, labels[..., None], -1)[..., 0]
         return jnp.mean(lse - gold)

@@ -20,7 +20,6 @@ import time
 T_START = time.time()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -62,12 +61,8 @@ def cell_metrics(spec: dict, cell: str, trace: bool) -> list:
 
 
 def reader(name: str):
-    path = os.path.join(HERE, "metrics", name + ".py")
-    sp = importlib.util.spec_from_file_location("metric_" + name.replace(
-        ".", "_"), path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read
+    import arch
+    return arch.load(os.path.join(HERE, "metrics", name + ".py")).read
 
 
 def configure_jax(root: str) -> None:
@@ -115,11 +110,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     sys.path.insert(0, os.path.join(ROOT, "src"))
     import harness
     import trace as TRC
+    import weights as W
 
     cell = harness.Cell(cfg, mix, seed, seconds, trace)
     cell.device_kind = devs[0].device_kind
     cell.build()
-    log(f"built {workload} ({cfg['name']}) on {devs[0].device_kind}")
+    log(f"built {workload} ({cfg['name']}, weights "
+        f"{W.param_bytes(cfg)} bytes) on {devs[0].device_kind}")
     cell.warm()
     cell.setup_s = time.time() - T_START
     events.active = False

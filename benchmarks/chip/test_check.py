@@ -37,12 +37,17 @@ def root(tmp_path_factory):
     spec["workloads"].append(dict(spec["workloads"][0],
                                   name="zamba2.chat_train",
                                   traffic="chat_train"))
+    # chat at four times the rate, so that both decode rows are busy in
+    # about half of the steps and the sample holds requests of each row
+    spec["workloads"].append(dict(spec["workloads"][0],
+                                  name="zamba2.chat_busy",
+                                  traffic="chat_busy"))
     os.makedirs(r / "benchmarks" / "chip" / "traffic")
     os.makedirs(r / "benchmarks" / "chip" / "configs")
     for c in spec["configs"]:
         shutil.copy(os.path.join(HERE, "testdata", "zamba2-tiny.json"),
                     r / c["file"])
-    for mix in ("chat", "chat_train"):
+    for mix in ("chat", "chat_train", "chat_busy"):
         shutil.copy(os.path.join(HERE, "testdata", mix + ".json"),
                     r / "benchmarks" / "chip" / "traffic" / (mix + ".json"))
     with open(r / "BENCHMARK.json", "w") as f:
@@ -85,18 +90,40 @@ def test_state_left_unchanged(root, monkeypatch):
 
 
 def test_half_the_rows_left_out(root, monkeypatch):
-    """A decode step that computes the first half of its rows and hands
-    the second half the first half's logits."""
+    """A decode step that leaves each row's own logits out and hands it
+    its neighbour's (rows shifted by one), so that the fault shows in
+    whichever row a checked request runs."""
+    import jax.numpy as jnp
     from repro.models import decode as D
     real = D.decode_step
+
+    def half(params, cfg, cache, tokens, pos):
+        logits, new = real(params, cfg, cache, tokens, pos)
+        return jnp.roll(logits, 1, 0), new
+    monkeypatch.setattr(D, "decode_step", half)
+    res = _run(root, "zamba2.chat")
+    assert not res["correct"] and "widest_logit_gap" in _failed(res)
+
+
+def test_half_the_rows_copied(root, monkeypatch):
+    """A decode step that computes the first half of its rows and hands
+    the second half the first half's logits, under traffic that keeps
+    both rows busy. Its widest gap reads far above a sound run's on the
+    same traffic."""
+    from repro.models import decode as D
+    real = D.decode_step
+    gap = "widest_logit_gap"
+    sound = _run(root, "zamba2.chat_busy")["compared"][gap]
 
     def half(params, cfg, cache, tokens, pos):
         logits, new = real(params, cfg, cache, tokens, pos)
         h = logits.shape[0] // 2
         return logits.at[h:].set(logits[:h]), new
     monkeypatch.setattr(D, "decode_step", half)
-    res = _run(root, "zamba2.chat")
-    assert not res["correct"] and "widest_logit_gap" in _failed(res)
+    res = _run(root, "zamba2.chat_busy")
+    assert not res["correct"] and gap in _failed(res)
+    assert res["compared"][gap]["value"] > \
+        10 * max(sound["value"], sound["limit"]), (sound, res["compared"])
 
 
 def test_token_altered(root, monkeypatch):

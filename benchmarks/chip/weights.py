@@ -1,11 +1,12 @@
 """Seeded weights, made on the device in one jitted call.
 
 The layout is the program's parameter tree (nested dicts, layers stacked
-on leading axes), written out here from a configuration file's sizes so
-that the plain reference can regenerate exactly the same values from the
-same seed without importing the program. ``run.py`` checks the layout
-against the program's own ``init_params`` shapes before it hands the
-weights over, so a change of layout fails loudly instead of silently.
+on leading axes), written out from a configuration file's sizes by the
+architecture's file (``archs/<program_arch>.py``), so that the plain
+reference can regenerate exactly the same values from the same seed
+without importing the program. ``harness.check_layout`` checks it
+against the program's own ``init_params`` shapes before the weights are
+handed over, so a change of layout fails loudly instead of silently.
 """
 from __future__ import annotations
 
@@ -13,6 +14,8 @@ import functools
 import math
 
 import numpy as np
+
+import arch
 
 
 def jax_seed(seed: int, salt: int = 0) -> int:
@@ -23,50 +26,10 @@ def jax_seed(seed: int, salt: int = 0) -> int:
 
 
 def layout(cfg: dict) -> dict:
-    """{path tuple: (shape, dtype name, init)} of every weight. ``init`` is
-    ("normal", std) | ("const", value) | ("a_log", lo, hi) | ("dt_bias",)."""
-    d, v = cfg["d_model"], cfg["vocab"]
-    wd = cfg["dtype"]
-    out = {("embed",): ((v, d), wd, ("normal", 0.02))}
-    ssm = cfg.get("ssm")
-    if cfg["family"] == "hybrid":
-        k = cfg["hybrid_shared_every"]
-        g = cfg["n_layers"] // k
-        st = (g, k - 1)
-        e, n = ssm["expand"] * d, ssm["state_dim"]
-        nh, w = e // ssm["headdim"], ssm["conv_width"]
-        m = ("groups", "mamba")
-        out.update({
-            m + ("in_proj",): (st + (d, 2 * e + 2 * n + nh), wd,
-                               ("normal", d ** -0.5)),
-            m + ("conv_w",): (st + (e + 2 * n, w), wd, ("normal", 0.2)),
-            m + ("conv_b",): (st + (e + 2 * n,), wd, ("normal", 0.02)),
-            m + ("dt_bias",): (st + (nh,), "float32", ("dt_bias",)),
-            m + ("A_log",): (st + (nh,), "float32", ("a_log", 1.0, 16.0)),
-            m + ("D",): (st + (nh,), "float32", ("const", 1.0)),
-            m + ("norm",): (st + (e,), wd, ("normal", 0.05)),
-            m + ("out_proj",): (st + (e, d), wd, ("normal", e ** -0.5)),
-            ("groups", "norm_m"): (st + (d,), wd, ("normal", 0.05)),
-            ("groups", "norm_attn"): ((g, d), wd, ("normal", 0.05)),
-            ("groups", "norm_mlp"): ((g, d), wd, ("normal", 0.05)),
-        })
-        h, kv, hd, f = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"], \
-            cfg["d_ff"]
-        a = ("shared", "attn")
-        out.update({
-            a + ("wq",): ((d, h, hd), wd, ("normal", d ** -0.5)),
-            a + ("wk",): ((d, kv, hd), wd, ("normal", d ** -0.5)),
-            a + ("wv",): ((d, kv, hd), wd, ("normal", d ** -0.5)),
-            a + ("wo",): ((h, hd, d), wd, ("normal", (h * hd) ** -0.5)),
-            ("shared", "mlp", "wi"): ((d, f), wd, ("normal", d ** -0.5)),
-            ("shared", "mlp", "wg"): ((d, f), wd, ("normal", d ** -0.5)),
-            ("shared", "mlp", "wo"): ((f, d), wd, ("normal", f ** -0.5)),
-        })
-    else:
-        raise ValueError(f"no weight layout for family {cfg['family']!r}")
-    out[("final_norm",)] = ((d,), wd, ("normal", 0.05))
-    out[("lm_head",)] = ((d, v), wd, ("normal", d ** -0.5))
-    return out
+    """{path tuple: (shape, dtype name, init)} of every weight, as the
+    architecture's file writes it out. ``init`` is ("normal", std) |
+    ("const", value) | ("a_log", lo, hi) | ("dt_bias",)."""
+    return arch.module(cfg).layout(cfg)
 
 
 def _leaf(key, shape, dtype, init):
